@@ -542,8 +542,11 @@ class FaceAuthExecutor:
         # offload runtime (camera/offload) can split it at any legal cut
         # point into a node-side and a cloud-side jit region while the
         # fused funnel below composes the very same functions — one
-        # implementation, two placements (DESIGN.md §10).
+        # implementation, two placements (DESIGN.md §10).  Each closure
+        # names its layer with a jax.named_scope, so every device op of the
+        # funnel carries it in its HLO op_name (no run-time cost).
 
+        @jax.named_scope("motion")
         def stage_motion(frames):
             """-- 1. motion gating + frame compaction to capacity M ------"""
             frames = frames.astype(jnp.float32)
@@ -560,6 +563,7 @@ class FaceAuthExecutor:
             mframes = jnp.take(frames, fidx, axis=0)
             return mframes, fidx, fvalid, motion, motion_dropped
 
+        @jax.named_scope("detect")
         def stage_detect(mframes, fvalid, det_c):
             """-- 2. fused VJ front-end (masked by the motion gate) ------
 
@@ -573,6 +577,7 @@ class FaceAuthExecutor:
             n_win_m = jnp.sum(dmask, axis=1).astype(jnp.int32)
             return dmask, n_win_m, casc_drop_m
 
+        @jax.named_scope("gather")
         def stage_gather(mframes, dmask, n_win_m, pos_c):
             """-- 3. capacity-padded window gather + 20x20 resample ------
 
@@ -610,6 +615,7 @@ class FaceAuthExecutor:
                     mframes, rows, cols)                       # (M, W, 20, 20)
             return patches, wsel, wvalid, win_dropped_m
 
+        @jax.named_scope("nn")
         def stage_nn(patches, wvalid, nn_c):
             """-- 4. int8 NN tail (both layers on the quant kernel) ------"""
             w1_q, b1, w2_q, b2, lut = nn_c
@@ -624,6 +630,7 @@ class FaceAuthExecutor:
             n_auth_m = jnp.sum(auth, axis=1).astype(jnp.int32)
             return s, auth, n_auth_m
 
+        @jax.named_scope("scatter")
         def stage_scatter(B, fidx, motion, motion_dropped, n_win_m,
                           casc_drop_m, wsel, wvalid, win_dropped_m,
                           s, auth, n_auth_m):

@@ -77,6 +77,7 @@ import numpy as np
 from repro.camera.serve.bytes_model import (FA_CUTS, fa_cut_bytes,
                                             fa_decision_bytes,
                                             fa_quiet_bytes)
+from repro.obs.ledger import rung_key
 
 _RESULT_KEYS = ("motion", "n_windows", "n_auth", "scores", "window_id",
                 "window_valid", "auth", "windows_dropped", "motion_dropped",
@@ -289,7 +290,7 @@ class StreamingServer:
         import jax
 
         from repro.camera.offload.link import BACKSCATTER
-        from repro.obs.telemetry import telemetry_on
+        from repro.obs.telemetry import null_span, telemetry_on
 
         self.base = base
         self.cfg = config
@@ -299,6 +300,7 @@ class StreamingServer:
         # path and every traced graph exactly as the pre-obs runtime
         self.telemetry = telemetry
         self._tel_on = telemetry_on(telemetry)
+        self._span = telemetry.span if self._tel_on else null_span
         self.h, self.w = base.det.grid.h, base.det.grid.w
         self._streams: dict = {}
         self._group_steps: dict = {}
@@ -307,7 +309,6 @@ class StreamingServer:
         self.tick_count = 0
         self.frames_completed = 0
         self.batch_lat_s: list = []      # wall seconds per dispatching tick
-        self.queue_delay_s: list = []    # simulated frame sojourn times
         self.last_link_report = None
         self.rejections: list = []
         self.total_enqueued = 0          # fleet seq-accounting counters:
@@ -609,15 +610,13 @@ class StreamingServer:
         if st.ladder.level != old:
             moves.append((st.sid, old, st.ladder.level))
             if self._tel_on:
-                from repro.obs.ledger import rung_key as _rk
-
                 self.telemetry.emit(
                     "ladder",
                     "descend" if st.ladder.level > old else "recover",
                     t=self.tick_count * self.cfg.tick_s,
                     tick=self.tick_count, sid=st.sid, old_level=old,
                     new_level=st.ladder.level,
-                    rung=_rk(tuple(st.ladder.rung)))
+                    rung=rung_key(tuple(st.ladder.rung)))
                 self.telemetry.counters.bump("serve.ladder_moves")
 
     # -- placement groups ------------------------------------------------------
@@ -827,249 +826,283 @@ class StreamingServer:
             st.queue.appendleft((a, f, sq))
 
     def tick(self, t: float) -> TickReport:
-        """One scheduler period at simulated time ``t``."""
+        """One scheduler period at simulated time ``t``.
+
+        With telemetry on, each phase is a span (``repro.obs``): the whole
+        call is ``serve.tick``; its children are ``serve.gather``, one
+        ``dispatch`` per rung group (holding ``serve.stage``,
+        ``serve.cascade`` and ``serve.readback``), ``serve.settle`` and
+        ``serve.admission``.
+        """
         import jax.numpy as jnp
 
         from repro.serve.engine import cascade_serve
 
         cfg = self.cfg
-        t0 = time.perf_counter()
-        events = []
-        if self._chaos is not None:
-            for kind, idx in self._chaos.events_at(self.tick_count):
-                (self.kill_device if kind == "kill"
-                 else self.restore_device)(idx)
-                events.append((kind, idx))
-        if self._tel_on and events and any(
-                s.cut is None for s in self._streams.values()):
-            # the local placement group re-shards at this tick's dispatch
-            self.telemetry.emit(
-                "failover", "local_group_reshard", t=t,
-                tick=self.tick_count,
-                events=[list(e) for e in events],
-                healthy=len(self._healthy()), dead=sorted(self._dead))
-        shed = self._collect_shed()
-        if self._tel_on:
-            for sr in shed:
-                self.telemetry.emit(
-                    "shed", "queue_overflow", t=t, tick=self.tick_count,
-                    sid=sr.sid, seq_lo=min(sr.seqs), seq_hi=max(sr.seqs),
-                    n=len(sr.seqs))
-                self.telemetry.counters.bump("serve.frames_shed",
-                                             len(sr.seqs))
-        ready = self._gather_ready(t)
-        gathered = [self._streams[rc.sid] for rc in ready]
-        groups: dict = {}
-        for rc in ready:
-            groups.setdefault(self._streams[rc.sid].rung, []).append(rc)
-
-        p99_link = (self.last_link_report.p99_latency_s
-                    if self.last_link_report is not None
-                    else self.link.latency_s)
-        completions, changes, moves = [], [], []
-        tick_bytes = {sid: 0.0 for sid in self._streams}
-        n_served = n_quiet = n_requeued = n_failed_tx = 0
-        dispatched = False
-        led_obs = []                     # (sid, rung, arrivals) per delivery
-        for rung, rcs in groups.items():
-            dispatched = True
-            cut, bits = rung
-            disp_t0 = time.perf_counter() if self._tel_on else 0.0
-            # pad the request stack to a capacity-multiple bucket so both
-            # the big model's (capacity, ...) batch and the scorer's see
-            # tick-invariant shapes: zero chunks are motionless, score
-            # below threshold, filtered before any compute
-            n = len(rcs)
-            b = self._bucket(n)
-            stack = np.zeros((b, cfg.chunk, self.h, self.w), np.float32)
-            for i, rc in enumerate(rcs):
-                stack[i] = rc.frames
-            outputs, served, stats = cascade_serve(
-                self._scores, self._group_step(rung), jnp.asarray(stack),
-                threshold=self._score_threshold, capacity=cfg.capacity)
-            served = np.asarray(served)
-            dropped = set(int(i) for i in np.asarray(
-                stats["dropped_capacity_idx"]) if i >= 0)
-            out_np = {k: np.asarray(v) for k, v in outputs.items()}
-            if self._tel_on:
-                from repro.obs.ledger import rung_key as _rk
-
-                # harvest funnel tel_ aux (present when the base executor
-                # is itself instrumented); out_np is already materialized
-                # host-side, so this adds no device sync
-                for k in [k for k in out_np if k.startswith("tel_")]:
-                    self.telemetry.counters.bump(
-                        "exec." + k[4:], int(out_np.pop(k).sum()))
-
-                self.telemetry.emit(
-                    "dispatch", f"group:{_rk(rung)}", t=t,
-                    dur=time.perf_counter() - disp_t0,
-                    tick=self.tick_count, n_chunks=n, bucket=b,
-                    n_served=int(served.sum()),
-                    n_capacity_dropped=len(dropped))
-                self.telemetry.counters.bump("serve.dispatches")
-            for i, rc in enumerate(rcs):
-                st = self._streams[rc.sid]
-                if i in dropped:                 # re-queue, oldest first
-                    n_requeued += 1
-                    st.requeues += 1
-                    self._requeue(st, rc)
-                    continue
-                if served[i]:
-                    n_served += 1
-                    result = {k: (out_np[k][i] if out_np[k][i].ndim == 0
-                                  else out_np[k][i][:rc.n_real])
-                              for k in _RESULT_KEYS}
-                    wire = float(out_np["wire_b"][i]) if cut else 0.0
-                    kind = "served"
-                    motion_n = int(result["motion"].sum())
-                    windows_n = int(result["window_valid"].sum())
-                else:                            # scorer-filtered: quiet
-                    n_quiet += 1
-                    q = self._quiet_result(cfg.chunk)
-                    result = {k: (q[k] if np.ndim(q[k]) == 0
-                                  else q[k][:rc.n_real]) for k in _RESULT_KEYS}
-                    wire = (fa_quiet_bytes(cut, bits, frames=cfg.chunk,
-                                           h=self.h, w=self.w)
-                            if cut else 0.0)
-                    kind = "quiet"
-                    motion_n = windows_n = 0
-                inj = self._injector(rc.sid)
-                payload_b = wire
-                if cut is not None and inj is not None:
-                    # chaos plane: the chunk transits the stream's fault
-                    # process; every attempt congests the shared uplink
-                    ok, on_air, att, lost, corrupt = \
-                        self._transmit(inj, wire, t)
-                    lat = (t - rc.arrivals[0]) + p99_link
-                    if self._tel_on:
-                        self.telemetry.emit(
-                            "link", "chunk_tx", t=t, tick=self.tick_count,
-                            sid=rc.sid, delivered=bool(ok), attempts=att,
-                            lost=lost, crc_fail=corrupt,
-                            payload_b=payload_b, on_air_b=on_air,
-                            seq_lo=rc.seqs[0], seq_hi=rc.seqs[-1],
-                            fault_id=self._chaos.fault_id(rc.sid))
-                        c = self.telemetry.counters
-                        c.bump("serve.link_attempts", att)
-                        c.bump("serve.link_lost", lost)
-                        c.bump("serve.link_crc_fail", corrupt)
-                        c.bump("serve.bytes_on_air", int(round(on_air)))
-                    if st.ladder is not None:
-                        self._observe_ladder(
-                            st, moves, rung=rung, delivered=ok,
-                            attempts=att, lost=lost, corrupt=corrupt,
-                            payload_b=payload_b, on_air=on_air,
-                            latency_s=lat)
-                    tick_bytes[rc.sid] = tick_bytes.get(rc.sid, 0.0) + on_air
-                    if not ok:
-                        # the cloud never received the payload — retried
-                        # next tick (possibly at a degraded rung), not lost
-                        n_failed_tx += 1
-                        st.tx_failures += 1
-                        self._requeue(st, rc)
-                        continue
-                    wire = on_air
-                elif cut is not None:
-                    tick_bytes[rc.sid] = tick_bytes.get(rc.sid, 0.0) + wire
-                elif (inj is not None and st.ladder is not None
-                        and st.ladder.level > 0):
-                    # ON_NODE rung: the decision beacon probes the channel
-                    # so hysteresis recovery has a signal
-                    beacon = fa_decision_bytes(rc.n_real)
-                    ok_b, on_air, att, lost, corrupt = \
-                        self._transmit(inj, beacon, t)
-                    self._observe_ladder(
-                        st, moves, rung=_ON_NODE, delivered=ok_b,
-                        attempts=att, lost=lost, corrupt=corrupt,
-                        payload_b=beacon, on_air=on_air, latency_s=0.0)
-                    tick_bytes[rc.sid] = tick_bytes.get(rc.sid, 0.0) + on_air
-                if (cut is not None and kind == "served"
-                        and self.controller is not None):
-                    # the byte model learns from the payload, never from
-                    # retransmissions — faults must not skew predictions
-                    self.controller.observe(cut, units=rc.n_real,
-                                            wire_bytes=payload_b)
-                st.stats.append((rc.n_real, motion_n, windows_n))
-                st.frames_done += rc.n_real
-                st.delivered_n += rc.n_real
-                st.last_served_seq = max(st.last_served_seq, rc.seqs[-1])
-                self.total_delivered += rc.n_real
-                st.deficit = max(0.0, st.deficit - float(cfg.chunk))
-                if st.cut is not None:
-                    st.frames_since_resolve += rc.n_real
+        span = self._span
+        with span("serve", "tick", t=t, tick=self.tick_count):
+            t0 = time.perf_counter()
+            with span("serve", "gather", t=t, tick=self.tick_count):
+                events = []
+                if self._chaos is not None:
+                    for kind, idx in self._chaos.events_at(self.tick_count):
+                        (self.kill_device if kind == "kill"
+                         else self.restore_device)(idx)
+                        events.append((kind, idx))
+                if self._tel_on and events and any(
+                        s.cut is None for s in self._streams.values()):
+                    # the local placement group re-shards at this tick's
+                    # dispatch
+                    self.telemetry.emit(
+                        "failover", "local_group_reshard", t=t,
+                        tick=self.tick_count,
+                        events=[list(e) for e in events],
+                        healthy=len(self._healthy()), dead=sorted(self._dead))
+                shed = self._collect_shed()
                 if self._tel_on:
-                    led_obs.append((rc.sid, rung, rc.arrivals))
+                    for sr in shed:
+                        self.telemetry.emit(
+                            "shed", "queue_overflow", t=t,
+                            tick=self.tick_count, sid=sr.sid,
+                            seq_lo=min(sr.seqs), seq_hi=max(sr.seqs),
+                            n=len(sr.seqs))
+                        self.telemetry.counters.bump("serve.frames_shed",
+                                                     len(sr.seqs))
+                ready = self._gather_ready(t)
+                gathered = [self._streams[rc.sid] for rc in ready]
+                groups: dict = {}
+                for rc in ready:
+                    groups.setdefault(self._streams[rc.sid].rung,
+                                      []).append(rc)
+
+            p99_link = (self.last_link_report.p99_latency_s
+                        if self.last_link_report is not None
+                        else self.link.latency_s)
+            completions, changes, moves = [], [], []
+            tick_bytes = {sid: 0.0 for sid in self._streams}
+            n_served = n_quiet = n_requeued = n_failed_tx = 0
+            dispatched = False
+            led_obs = []                 # (sid, rung, arrivals) per delivery
+            for rung, rcs in groups.items():
+                dispatched = True
+                cut, bits = rung
+                name = f"group:{rung_key(rung)}" if self._tel_on else ""
+                with span("dispatch", name, t=t,
+                          tick=self.tick_count) as disp:
+                    # pad the request stack to a capacity-multiple bucket
+                    # so both the big model's (capacity, ...) batch and the
+                    # scorer's see tick-invariant shapes: zero chunks are
+                    # motionless, score below threshold, filtered before
+                    # any compute
+                    n = len(rcs)
+                    b = self._bucket(n)
+                    with span("serve", "stage", t=t, tick=self.tick_count):
+                        stack = np.zeros((b, cfg.chunk, self.h, self.w),
+                                         np.float32)
+                        for i, rc in enumerate(rcs):
+                            stack[i] = rc.frames
+                        stack = jnp.asarray(stack)
+                    with span("serve", "cascade", t=t, tick=self.tick_count):
+                        outputs, served, stats = cascade_serve(
+                            self._scores, self._group_step(rung), stack,
+                            threshold=self._score_threshold,
+                            capacity=cfg.capacity)
+                    with span("serve", "readback", t=t,
+                              tick=self.tick_count):
+                        served = np.asarray(served)
+                        dropped = set(int(i) for i in np.asarray(
+                            stats["dropped_capacity_idx"]) if i >= 0)
+                        out_np = {k: np.asarray(v)
+                                  for k, v in outputs.items()}
+                    if self._tel_on:
+                        # harvest funnel tel_ aux (present when the base
+                        # executor is itself instrumented); out_np is
+                        # already materialized host-side, so this adds no
+                        # device sync
+                        for k in [k for k in out_np if k.startswith("tel_")]:
+                            self.telemetry.counters.bump(
+                                "exec." + k[4:], int(out_np.pop(k).sum()))
+                        disp.args.update(
+                            n_chunks=n, bucket=b,
+                            n_served=int(served.sum()),
+                            n_capacity_dropped=len(dropped))
+                        self.telemetry.counters.bump("serve.dispatches")
+                with span("serve", "settle", t=t, tick=self.tick_count):
+                    for i, rc in enumerate(rcs):
+                        st = self._streams[rc.sid]
+                        if i in dropped:         # re-queue, oldest first
+                            n_requeued += 1
+                            st.requeues += 1
+                            self._requeue(st, rc)
+                            continue
+                        if served[i]:
+                            n_served += 1
+                            result = {k: (out_np[k][i]
+                                          if out_np[k][i].ndim == 0
+                                          else out_np[k][i][:rc.n_real])
+                                      for k in _RESULT_KEYS}
+                            wire = (float(out_np["wire_b"][i]) if cut
+                                    else 0.0)
+                            kind = "served"
+                            motion_n = int(result["motion"].sum())
+                            windows_n = int(result["window_valid"].sum())
+                        else:                    # scorer-filtered: quiet
+                            n_quiet += 1
+                            q = self._quiet_result(cfg.chunk)
+                            result = {k: (q[k] if np.ndim(q[k]) == 0
+                                          else q[k][:rc.n_real])
+                                      for k in _RESULT_KEYS}
+                            wire = (fa_quiet_bytes(cut, bits,
+                                                   frames=cfg.chunk,
+                                                   h=self.h, w=self.w)
+                                    if cut else 0.0)
+                            kind = "quiet"
+                            motion_n = windows_n = 0
+                        inj = self._injector(rc.sid)
+                        payload_b = wire
+                        if cut is not None and inj is not None:
+                            # chaos plane: the chunk transits the stream's
+                            # fault process; every attempt congests the
+                            # shared uplink
+                            ok, on_air, att, lost, corrupt = \
+                                self._transmit(inj, wire, t)
+                            lat = (t - rc.arrivals[0]) + p99_link
+                            if self._tel_on:
+                                self.telemetry.emit(
+                                    "link", "chunk_tx", t=t,
+                                    tick=self.tick_count, sid=rc.sid,
+                                    delivered=bool(ok), attempts=att,
+                                    lost=lost, crc_fail=corrupt,
+                                    payload_b=payload_b, on_air_b=on_air,
+                                    seq_lo=rc.seqs[0], seq_hi=rc.seqs[-1],
+                                    fault_id=self._chaos.fault_id(rc.sid))
+                                c = self.telemetry.counters
+                                c.bump("serve.link_attempts", att)
+                                c.bump("serve.link_lost", lost)
+                                c.bump("serve.link_crc_fail", corrupt)
+                                c.bump("serve.bytes_on_air",
+                                       int(round(on_air)))
+                            if st.ladder is not None:
+                                self._observe_ladder(
+                                    st, moves, rung=rung, delivered=ok,
+                                    attempts=att, lost=lost, corrupt=corrupt,
+                                    payload_b=payload_b, on_air=on_air,
+                                    latency_s=lat)
+                            tick_bytes[rc.sid] = (tick_bytes.get(rc.sid, 0.0)
+                                                  + on_air)
+                            if not ok:
+                                # the cloud never received the payload —
+                                # retried next tick (possibly at a degraded
+                                # rung), not lost
+                                n_failed_tx += 1
+                                st.tx_failures += 1
+                                self._requeue(st, rc)
+                                continue
+                            wire = on_air
+                        elif cut is not None:
+                            tick_bytes[rc.sid] = (tick_bytes.get(rc.sid, 0.0)
+                                                  + wire)
+                        elif (inj is not None and st.ladder is not None
+                                and st.ladder.level > 0):
+                            # ON_NODE rung: the decision beacon probes the
+                            # channel so hysteresis recovery has a signal
+                            beacon = fa_decision_bytes(rc.n_real)
+                            ok_b, on_air, att, lost, corrupt = \
+                                self._transmit(inj, beacon, t)
+                            self._observe_ladder(
+                                st, moves, rung=_ON_NODE, delivered=ok_b,
+                                attempts=att, lost=lost, corrupt=corrupt,
+                                payload_b=beacon, on_air=on_air,
+                                latency_s=0.0)
+                            tick_bytes[rc.sid] = (tick_bytes.get(rc.sid, 0.0)
+                                                  + on_air)
+                        if (cut is not None and kind == "served"
+                                and self.controller is not None):
+                            # the byte model learns from the payload, never
+                            # from retransmissions — faults must not skew
+                            # predictions
+                            self.controller.observe(cut, units=rc.n_real,
+                                                    wire_bytes=payload_b)
+                        st.stats.append((rc.n_real, motion_n, windows_n))
+                        st.frames_done += rc.n_real
+                        st.delivered_n += rc.n_real
+                        st.last_served_seq = max(st.last_served_seq,
+                                                 rc.seqs[-1])
+                        self.total_delivered += rc.n_real
+                        st.deficit = max(0.0, st.deficit - float(cfg.chunk))
+                        if st.cut is not None:
+                            st.frames_since_resolve += rc.n_real
+                        if self._tel_on:
+                            led_obs.append((rc.sid, rung, rc.arrivals))
+                            c = self.telemetry.counters
+                            c.bump("serve.frames_delivered", rc.n_real)
+                            c.bump("serve.chunks_" + kind)
+                        completions.append(Completion(
+                            sid=rc.sid, t=t, n_frames=rc.n_real, kind=kind,
+                            result=result, wire_bytes=wire, seqs=rc.seqs))
+
+            with span("serve", "settle", t=t, tick=self.tick_count):
+                # DRR normalization: shift gathered deficits down by their
+                # min so credits stay bounded (relative order — the only
+                # thing the grant sort reads — is unchanged)
+                if gathered:
+                    m = min(st.deficit for st in gathered)
+                    if m > 0.0:
+                        for st in gathered:
+                            st.deficit -= m
+
+                batch_s = time.perf_counter() - t0
+                if dispatched:
+                    self.batch_lat_s.append(batch_s)
+                self.frames_completed += sum(c.n_frames for c in completions)
+                if self._tel_on:
+                    for sid, rung, arrivals in led_obs:
+                        for a in arrivals:
+                            self.telemetry.ledger.observe_latency(
+                                sid, rung, (t + batch_s) - a)
+                    depths = [len(s.queue) for s in self._streams.values()]
+                    self.telemetry.emit(
+                        "tick", f"tick{self.tick_count}", t=t, dur=batch_s,
+                        tick=self.tick_count, n_streams=len(self._streams),
+                        n_ready=len(ready), n_served=n_served,
+                        n_quiet=n_quiet, n_requeued=n_requeued,
+                        n_failed_tx=n_failed_tx,
+                        queue_frames=int(sum(depths)),
+                        queue_max=int(max(depths, default=0)),
+                        deficit_max=float(max(
+                            (s.deficit for s in self._streams.values()),
+                            default=0.0)),
+                        bytes_sent=float(sum(tick_bytes.values())))
                     c = self.telemetry.counters
-                    c.bump("serve.frames_delivered", rc.n_real)
-                    c.bump("serve.chunks_" + kind)
-                completions.append(Completion(
-                    sid=rc.sid, t=t, n_frames=rc.n_real, kind=kind,
-                    result=result, wire_bytes=wire, seqs=rc.seqs))
+                    c.bump("serve.ticks")
+                    c.bump("serve.chunks_requeued", n_requeued)
+                    c.bump("serve.tx_failures", n_failed_tx)
 
-        # DRR normalization: shift gathered deficits down by their min so
-        # credits stay bounded (relative order — the only thing the grant
-        # sort reads — is unchanged)
-        if gathered:
-            m = min(st.deficit for st in gathered)
-            if m > 0.0:
-                for st in gathered:
-                    st.deficit -= m
+            with span("serve", "admission", t=t, tick=self.tick_count):
+                # byte traces + congestion report
+                for sid, st in self._streams.items():
+                    st.trace.append(tick_bytes.get(sid, 0.0))
+                self.tick_count += 1
+                if (self.tick_count % cfg.link_window == 0
+                        and any(s.cut is not None
+                                for s in self._streams.values())):
+                    self._refresh_link_report()
+                # refresh measured offered load for admission
+                for st in self._streams.values():
+                    if st.cut is not None and st.trace:
+                        st.declared_bps = (sum(st.trace)
+                                           / (len(st.trace) * cfg.tick_s))
 
-        batch_s = time.perf_counter() - t0
-        if dispatched:
-            self.batch_lat_s.append(batch_s)
-        # simulated frame sojourn: queue wait + this tick's dispatch
-        # (at most one ready chunk per stream per tick, so sid identifies it)
-        completed_sids = {c.sid for c in completions}
-        for rc in ready:
-            if rc.sid in completed_sids:
-                self.queue_delay_s.extend(
-                    (t + batch_s) - a for a in rc.arrivals)
-        self.frames_completed += sum(c.n_frames for c in completions)
-        if self._tel_on:
-            for sid, rung, arrivals in led_obs:
-                for a in arrivals:
-                    self.telemetry.ledger.observe_latency(
-                        sid, rung, (t + batch_s) - a)
-            depths = [len(s.queue) for s in self._streams.values()]
-            self.telemetry.emit(
-                "tick", f"tick{self.tick_count}", t=t, dur=batch_s,
-                tick=self.tick_count, n_streams=len(self._streams),
-                n_ready=len(ready), n_served=n_served, n_quiet=n_quiet,
-                n_requeued=n_requeued, n_failed_tx=n_failed_tx,
-                queue_frames=int(sum(depths)),
-                queue_max=int(max(depths, default=0)),
-                deficit_max=float(max(
-                    (s.deficit for s in self._streams.values()),
-                    default=0.0)),
-                bytes_sent=float(sum(tick_bytes.values())))
-            c = self.telemetry.counters
-            c.bump("serve.ticks")
-            c.bump("serve.chunks_requeued", n_requeued)
-            c.bump("serve.tx_failures", n_failed_tx)
-
-        # byte traces + congestion report
-        for sid, st in self._streams.items():
-            st.trace.append(tick_bytes.get(sid, 0.0))
-        self.tick_count += 1
-        if (self.tick_count % cfg.link_window == 0
-                and any(s.cut is not None for s in self._streams.values())):
-            self._refresh_link_report()
-        # refresh measured offered load for admission
-        for st in self._streams.values():
-            if st.cut is not None and st.trace:
-                st.declared_bps = (sum(st.trace)
-                                   / (len(st.trace) * cfg.tick_s))
-
-        resolves = self._maybe_resolve(changes)
-        if self._tel_on and resolves:
-            self.telemetry.counters.bump("serve.resolves_fired", resolves)
-            for sid, old_cut, new_cut in changes:
-                self.telemetry.emit(
-                    "dispatch", "cut_change", t=t, tick=self.tick_count,
-                    sid=sid, old_cut=str(old_cut), new_cut=str(new_cut))
-        self._reap_drained()
+                resolves = self._maybe_resolve(changes)
+                if self._tel_on and resolves:
+                    self.telemetry.counters.bump("serve.resolves_fired",
+                                                 resolves)
+                    for sid, old_cut, new_cut in changes:
+                        self.telemetry.emit(
+                            "dispatch", "cut_change", t=t,
+                            tick=self.tick_count, sid=sid,
+                            old_cut=str(old_cut), new_cut=str(new_cut))
+                self._reap_drained()
         return TickReport(
             t=t, n_ready=len(ready), n_served=n_served, n_quiet=n_quiet,
             n_requeued=n_requeued, batch_s=batch_s,
@@ -1156,8 +1189,8 @@ class StreamingServer:
         descriptors, ladder levels, DRR credits, seq counters, controller
         windows — rides the JSON ``extra``.  Call between ticks only: a
         mid-tick snapshot would double-serve in-flight chunks on restore.
-        Wall-clock metric lists (``batch_lat_s``/``queue_delay_s``) are
-        host measurements, not server state, and reset on restore.
+        The wall-clock metric list (``batch_lat_s``) is a
+        host measurement, not server state, and reset on restore.
         """
         from repro.ckpt.checkpoint import save_checkpoint
 

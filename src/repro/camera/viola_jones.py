@@ -632,10 +632,11 @@ class FusedDetector:
                 return res.mask, res.n_survivors, res.dropped
 
             frames = frames.astype(jnp.float32)
-            ii = frame_integral(frames, use_pallas=use_pallas,
-                                interpret=interpret)
-            ii2 = frame_integral(frames * frames, use_pallas=use_pallas,
-                                 interpret=interpret)
+            with jax.named_scope("integral"):
+                ii = frame_integral(frames, use_pallas=use_pallas,
+                                    interpret=interpret)
+                ii2 = frame_integral(frames * frames, use_pallas=use_pallas,
+                                     interpret=interpret)
             b = frames.shape[0]
             return jax.vmap(one_frame)(ii.reshape(b, -1), ii2.reshape(b, -1))
 

@@ -140,7 +140,10 @@ def compacting_cascade(
         else:
             dropped_here = jnp.int32(0)
 
-        scores = st.fn(cur_items)
+        # the stage's name scopes its device ops (HLO op_name metadata,
+        # read off a profiler trace; free at run time)
+        with jax.named_scope(st.name):
+            scores = st.fn(cur_items)
         scores = jnp.where(cur_mask, scores, -jnp.inf)
         cur_mask = cur_mask & (scores >= st.threshold)
 

@@ -8,13 +8,37 @@ reconstructed offline from the JSONL alone (DESIGN.md §15):
 * ``tick``    — the server tick index the event belongs to (-1 if n/a);
 * ``sid``     — stream id ("" if fleet-wide).
 
-Kinds used by the instrumented stack: ``tick`` (one span per server
-tick), ``dispatch`` (one span per rung-group jit dispatch), ``link``
-(one event per transmit, args carry attempts/lost/crc), ``chaos``
-(injected device events), ``ladder`` (rung transitions), ``failover``
-(pmap<->vmap re-shard), ``shed`` (DRR shedding), ``ckpt``
+* ``parent``  — the eid of the span that was open when this one began
+  (-1 at top level, and for every instant event).
+
+Kinds used by the instrumented stack: ``tick`` (one summary span per
+server tick), ``dispatch`` (one span per rung-group jit dispatch),
+``link`` (one event per transmit, args carry attempts/lost/crc),
+``chaos`` (injected device events), ``ladder`` (rung transitions),
+``failover`` (pmap<->vmap re-shard), ``shed`` (DRR shedding), ``ckpt``
 (checkpoint/restore).  The set is open — the schema is the record
 shape, not the kind vocabulary.
+
+Nested spans come from ``Telemetry.span`` (DESIGN.md §15).  The serving
+tick records ``serve.tick`` around the whole call, with the children
+``serve.gather`` (chaos events, shedding, the ready chunks and their
+grouping), ``dispatch`` (one per rung group; its children are
+``serve.stage`` for the host stack and its transfer, ``serve.cascade``
+for ``cascade_serve`` and ``serve.readback`` for the results' copy to
+the host), ``serve.settle`` (per-chunk bookkeeping, link, ladder, DRR,
+ledger) and ``serve.admission`` (link report, offered load, re-solves,
+reaping).  Each span is also a ``jax.profiler.TraceAnnotation`` named
+``repro.<kind>.<name>``, so a profiler trace holds it on the device
+trace's clock beside the device's operations.
+
+The funnel's device program names its layers with ``jax.named_scope``,
+always on (HLO metadata, no run-time cost): ``motion``, ``detect`` (with
+``integral`` around the summed-area tables and ``vj0``..``vjN`` around
+each Haar stage, from ``core.cascade.compacting_cascade``), ``gather``,
+``nn`` and ``scatter``.  ``cascade_serve`` names ``scorer`` and
+``capacity`` the same way, but it runs eagerly in the serving tick, and
+an eager operation is compiled alone under its own name, so those two
+scopes reach the device only where the cascade is traced inside a jit.
 """
 
 from __future__ import annotations
@@ -37,6 +61,7 @@ class TraceRecord:
     tick: int
     sid: str
     args: dict
+    parent: int = -1    # eid of the enclosing open span, -1 if none
 
     def to_json(self) -> dict:
         return dataclasses.asdict(self)
@@ -47,7 +72,8 @@ class TraceRecord:
                    kind=str(d["kind"]), name=str(d["name"]),
                    t=float(d["t"]), dur=float(d["dur"]),
                    tick=int(d["tick"]), sid=str(d["sid"]),
-                   args=dict(d.get("args", {})))
+                   args=dict(d.get("args", {})),
+                   parent=int(d.get("parent", -1)))
 
 
 class TraceRecorder:
@@ -67,13 +93,20 @@ class TraceRecorder:
     def __len__(self) -> int:
         return len(self._buf)
 
-    def emit(self, kind: str, name: str, *, t: float = 0.0, dur: float = 0.0,
-             tick: int = -1, sid: str = "", **args) -> int:
-        rec = TraceRecord(eid=self._next_eid, run_id=self.run_id,
-                          kind=str(kind), name=str(name), t=float(t),
-                          dur=float(dur), tick=int(tick), sid=str(sid),
-                          args=args)
+    def reserve(self) -> int:
+        """A fresh eid for a record emitted later (a span's eid is taken
+        when it opens, so its children can name it as their parent)."""
+        eid = self._next_eid
         self._next_eid += 1
+        return eid
+
+    def emit(self, kind: str, name: str, *, t: float = 0.0, dur: float = 0.0,
+             tick: int = -1, sid: str = "", parent: int = -1,
+             eid: Optional[int] = None, **args) -> int:
+        rec = TraceRecord(eid=self.reserve() if eid is None else int(eid),
+                          run_id=self.run_id, kind=str(kind), name=str(name),
+                          t=float(t), dur=float(dur), tick=int(tick),
+                          sid=str(sid), args=args, parent=int(parent))
         if self._buf.maxlen and len(self._buf) == self._buf.maxlen:
             self.dropped += 1
         self._buf.append(rec)
@@ -128,8 +161,8 @@ def perfetto_events(records: Iterable[TraceRecord]) -> List[dict]:
         tid = lanes.setdefault(r.kind, len(lanes) + 1)
         ev = {"name": r.name, "cat": r.kind, "pid": 1, "tid": tid,
               "ts": r.t * 1e6,
-              "args": {**r.args, "eid": r.eid, "tick": r.tick,
-                       "sid": r.sid, "run_id": r.run_id}}
+              "args": {**r.args, "eid": r.eid, "parent": r.parent,
+                       "tick": r.tick, "sid": r.sid, "run_id": r.run_id}}
         if r.dur > 0:
             ev["ph"] = "X"
             ev["dur"] = r.dur * 1e6

@@ -323,6 +323,155 @@ class TestTraceIds:
 
 
 # ---------------------------------------------------------------------------
+# nested spans (Telemetry.span) and the serving tick's phases
+# ---------------------------------------------------------------------------
+
+TICK_CHILDREN = {"gather", "stage", "cascade", "readback", "settle",
+                 "admission"}
+
+
+def _drive(ex, frames, telemetry):
+    """Two streams, three ticks; the completions' results per tick."""
+    srv = _server(ex, telemetry=telemetry)
+    for sid in ("a", "b"):
+        srv.register(sid, fps=1.0)
+    out = []
+    for k in range(3):
+        for j, sid in enumerate(("a", "b")):
+            for i in range(2):
+                srv.enqueue(sid, frames[(4 * k + 2 * j + i) % len(frames)],
+                            t=float(k) + 0.1 * i)
+        rep = srv.tick(float(k) + 1.0)
+        out.append([(c.sid, c.seqs, c.kind,
+                     {key: np.asarray(v) for key, v in c.result.items()})
+                    for c in rep.completions])
+    return out
+
+
+class TestSpans:
+    def test_nested_spans_name_parents_and_real_starts(self, monkeypatch):
+        import time as _time
+
+        clock = iter([10.0, 10.5, 11.0, 12.0, 14.0, 14.25])
+        monkeypatch.setattr(_time, "perf_counter", lambda: next(clock))
+        tel = Telemetry(enabled=True)
+        with tel.span("serve", "tick", t=100.0, tick=3) as outer:
+            with tel.span("serve", "gather", t=100.0, tick=3):
+                pass
+            with tel.span("dispatch", "group:local", t=100.0,
+                          tick=3) as d:
+                d.args.update(n_chunks=2)
+        recs = {r.name: r for r in tel.trace.records()}
+        assert recs["tick"].eid == outer.eid and recs["tick"].parent == -1
+        assert recs["gather"].parent == outer.eid
+        assert recs["group:local"].parent == outer.eid
+        # starts: the caller's t plus the time since the outermost began
+        assert recs["tick"].t == 100.0 and recs["tick"].dur == 4.25
+        assert recs["gather"].t == 100.5 and recs["gather"].dur == 0.5
+        assert recs["group:local"].t == 102.0
+        assert recs["group:local"].args == {"n_chunks": 2}
+        assert recs["tick"].tick == 3
+        # an eid is taken when the span opens: parents before children
+        assert recs["tick"].eid < recs["gather"].eid < recs["group:local"].eid
+
+    def test_disabled_span_is_the_shared_null_context(self, monkeypatch):
+        from repro.obs.telemetry import NULL_SPAN
+
+        def boom(*a, **k):
+            raise AssertionError("TraceAnnotation with telemetry off")
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+        tel = Telemetry(enabled=False)
+        span = tel.span("serve", "tick", t=0.0)
+        assert span is NULL_SPAN
+        with span as handle:
+            assert handle is None
+        assert len(tel.trace) == 0
+
+    def test_record_without_parent_loads_as_top_level(self, tmp_path):
+        d = {"eid": 4, "run_id": "r", "kind": "tick", "name": "t0",
+             "t": 1.0, "dur": 0.5, "tick": 0, "sid": "", "args": {}}
+        assert TraceRecord.from_json(d).parent == -1
+        tr = TraceRecorder()
+        tr.emit("serve", "gather", t=0.0, parent=7)
+        path = str(tmp_path / "t.jsonl")
+        tr.to_jsonl(path)
+        assert TraceRecorder.load_jsonl(path)[0].parent == 7
+        (ev,) = perfetto_events(tr.records())
+        assert ev["args"]["parent"] == 7
+
+    def test_disabled_tick_makes_no_annotation_and_matches_enabled(
+            self, fa_setup, monkeypatch):
+        make, ex, frames, base = fa_setup
+        on = _drive(ex, frames, Telemetry(enabled=True))
+
+        def boom(*a, **k):
+            raise AssertionError("TraceAnnotation with telemetry off")
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+        for tel in (None, Telemetry(enabled=False)):
+            off = _drive(ex, frames, tel)
+            assert len(off) == len(on)
+            for got, want in zip(off, on):
+                assert [c[:3] for c in got] == [c[:3] for c in want]
+                for (_s, _q, _k, r1), (_s2, _q2, _k2, r2) in zip(got, want):
+                    assert r1.keys() == r2.keys()
+                    assert all(np.array_equal(r1[k], r2[k]) for k in r1)
+            if tel is not None:
+                assert len(tel.trace) == 0
+
+    def test_enabled_tick_records_its_phases_as_children(self, fa_setup):
+        make, ex, frames, base = fa_setup
+        tel = Telemetry(enabled=True)
+        _drive(ex, frames, tel)
+        recs = tel.trace.records()
+        by_eid = {r.eid: r for r in recs}
+        ticks = [r for r in recs if (r.kind, r.name) == ("serve", "tick")]
+        assert len(ticks) == 3
+        for tick in ticks:
+            kids = [r for r in recs if r.parent == tick.eid]
+            disp = [r for r in kids if r.kind == "dispatch"]
+            assert len(disp) == 1 and disp[0].name == "group:local"
+            grand = [r for r in recs if r.parent == disp[0].eid]
+            assert [r.name for r in grand] == ["stage", "cascade",
+                                               "readback"]
+            names = {r.name for r in kids if r.kind == "serve"}
+            assert names | {r.name for r in grand} == TICK_CHILDREN
+            assert all(r.tick == tick.tick for r in kids + grand)
+            for parent, children in ((tick, kids), (disp[0], grand)):
+                assert sum(r.dur for r in children) <= parent.dur
+                assert all(parent.t <= r.t and r.t + r.dur
+                           <= parent.t + parent.dur + 1e-9
+                           for r in children)
+            assert all(by_eid[r.parent] is tick for r in kids)
+        # the dispatch record keeps its fields, and the tick record stays
+        (d0,) = [r for r in recs if r.kind == "dispatch"
+                 and r.parent == ticks[0].eid]
+        assert {"n_chunks", "bucket", "n_served",
+                "n_capacity_dropped"} <= set(d0.args)
+        assert len(tel.trace.records("tick")) == 3
+
+    def test_lowered_batch_step_names_the_funnel_layers(self, fa_setup):
+        import re
+
+        make, ex, frames, base = fa_setup
+        step = ex.batch_step(2, 2)
+        fr = jnp.asarray(np.stack([frames[:2], frames[2:4]]))
+        text = jax.jit(step._core).lower(
+            fr, jnp.ones((2,), bool), *step._consts).as_text(
+                debug_info=True)
+        scopes = set()
+        for path in re.findall(r'loc\("([^"]+)"', text):
+            # vmap marks a scope inside it as vmap(<scope>)
+            scopes.update(re.sub(r"^(?:vmap\()+(\w+)\)+$", r"\1", part)
+                          for part in path.split("/")[:-1])
+        assert {"motion", "detect", "integral", "vj0", "gather", "nn",
+                "scatter"} <= scopes
+        stages = int(len(ex.det.tables.stage_sizes))
+        assert {f"vj{i}" for i in range(stages)} <= scopes
+
+
+# ---------------------------------------------------------------------------
 # satellite 2 regression: sorted-sid shed + seq_audit order
 # ---------------------------------------------------------------------------
 
