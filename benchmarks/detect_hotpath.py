@@ -8,7 +8,8 @@ Three timed configurations on the paper's 176x144 security workload:
           Python loop over features (cascade_apply), no early-exit savings;
   ref   — the scaled-feature golden oracle (detect_faces), same per-window
           structure with native-resolution windows;
-  new   — FusedDetector: one frame integral, gathered Haar corner taps,
+  new   — FusedDetector: one frame integral, Haar corner taps (sliced in
+          stage 0, gathered in the survivor stages),
           compacting cascade with measured capacities.
 
 Also reports the FLOP saving compaction realizes vs the masked oracle —

@@ -445,7 +445,7 @@ class FaceAuthExecutor:
        granularity, so downstream work scales with the motion rate while
        shapes stay static.
     2. **Fused detection** — ``FusedDetector``'s traceable core (one frame
-       integral image, gathered Haar corner taps, compacting cascade).
+       integral image, Haar corner taps, compacting cascade).
     3. **Capacity-padded window gather** — per frame, up to
        ``window_capacity`` detected windows are gathered and
        nearest-resampled to 20x20 *on device* (integer-exact replica of

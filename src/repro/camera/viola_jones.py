@@ -16,7 +16,8 @@ Paper §III-B reproduced end to end:
 Execution model (DESIGN.md §3): the production path is the *frame-resident
 fused front-end* (:class:`FusedDetector` / :func:`detect_faces_batch`) —
 one frame-level integral image, every window at every scale evaluated by
-batched corner-tap gathers into that single table, and the stage loop
+corner taps into that single table (strided slices for stage 0, which
+scores the whole grid; gathers for the survivor stages), and the stage loop
 routed through ``core.cascade.compacting_cascade`` so later stages only
 compute on survivors.  :func:`detect_faces` is the slow reference (golden
 oracle): per-window integral images and a Python loop over features,
@@ -411,7 +412,7 @@ def detect_faces(cascade: Cascade, frame: np.ndarray, scale_factor=1.25,
 
 # ---------------------------------------------------------------------------
 # Frame-resident fused front-end (DESIGN.md §3): one integral image,
-# gathered Haar features, compacting cascade
+# Haar features as corner taps, compacting cascade
 # ---------------------------------------------------------------------------
 
 _NORM_W = np.array([1.0, -1.0, -1.0, 1.0], np.float32)   # window-sum corners
@@ -421,7 +422,8 @@ _NORM_W = np.array([1.0, -1.0, -1.0, 1.0], np.float32)   # window-sum corners
 class ScanGrid:
     """Static scan geometry for one (frame shape, scan parameters) pair:
     every (y, x, win) position, each window's flat *base* index into the
-    zero-padded (h+1, w+1) integral image, and its pyramid-scale id."""
+    zero-padded (h+1, w+1) integral image, its pyramid-scale id, and each
+    scale's windows as one row-major rectangle of top-left corners."""
 
     h: int
     w: int
@@ -429,6 +431,23 @@ class ScanGrid:
     scales: tuple                # distinct window sizes, pyramid order
     bases: np.ndarray            # (n,) int32: y * (w + 1) + x
     scale_id: np.ndarray         # (n,) int32 index into ``scales``
+    rects: tuple                 # per scale (y0, x0, ny, nx, sy, sx)
+
+
+def _scale_rect(corners: list) -> tuple:
+    """(y0, x0, ny, nx, sy, sx) of one scale's window corners, which must
+    be exactly the row-major grid {(y0 + i*sy, x0 + j*sx)}."""
+    ys = sorted({y for y, _x in corners})
+    xs = sorted({x for _y, x in corners})
+    sy = ys[1] - ys[0] if len(ys) > 1 else 1
+    sx = xs[1] - xs[0] if len(xs) > 1 else 1
+    rect = (ys[0], xs[0], len(ys), len(xs), sy, sx)
+    if corners != [(ys[0] + i * sy, xs[0] + j * sx)
+                   for i in range(len(ys)) for j in range(len(xs))]:
+        raise ValueError(f"the windows of one scale are not a single "
+                         f"row-major grid (nearest fit {rect}); a scale "
+                         f"factor that repeats a window size gives two")
+    return rect
 
 
 @functools.lru_cache(maxsize=32)
@@ -441,8 +460,10 @@ def build_scan_grid(h: int, w: int, scale_factor: float = 1.25,
             scales.append(win)
         sid.append(len(scales) - 1)
     bases = np.array([y * (w + 1) + x for (y, x, _win) in pos], np.int32)
+    rects = tuple(_scale_rect([(y, x) for (y, x, win) in pos if win == s])
+                  for s in scales)
     return ScanGrid(h, w, tuple(pos), tuple(scales), bases,
-                    np.array(sid, np.int32))
+                    np.array(sid, np.int32), rects)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -453,7 +474,6 @@ class GatherTables:
 
     offsets: np.ndarray          # (n_scales, n_weak, CORNER_SLOTS) int32
     weights: np.ndarray          # (n_weak, CORNER_SLOTS) f32, 0-padded
-    norm_offsets: np.ndarray     # (n_scales, 4) int32 window-sum taps
     areas: np.ndarray            # (n_scales,) f32 win^2
     thresholds: np.ndarray       # (n_weak,) stump params
     polarity: np.ndarray
@@ -475,12 +495,9 @@ def build_gather_tables(cascade: Cascade, grid: ScanGrid) -> GatherTables:
             for c, (dy, dx, _wv) in enumerate(
                     feature_corners(scale_feature(f, win))):
                 offsets[s, k, c] = dy * stride + dx
-    norm_offsets = np.array(
-        [[win * stride + win, win, win * stride, 0] for win in grid.scales],
-        np.int32)
     areas = np.array([float(win * win) for win in grid.scales], np.float32)
     return GatherTables(
-        offsets, weights, norm_offsets, areas,
+        offsets, weights, areas,
         np.asarray(cascade.thresholds, np.float32),
         np.asarray(cascade.polarity, np.float32),
         np.asarray(cascade.alphas, np.float32),
@@ -488,11 +505,24 @@ def build_gather_tables(cascade: Cascade, grid: ScanGrid) -> GatherTables:
         np.asarray(cascade.stage_thresholds, np.float32))
 
 
+def _stage_vote(resp, inv_norm, thresholds, polarity, alphas):
+    """Stump vote and stage reduce over raw responses (sz, *windows)."""
+    def col(v):
+        return v.reshape(v.shape + (1,) * (resp.ndim - 1))
+
+    resp = resp * inv_norm[None]
+    pred = col(polarity) * jnp.sign(resp - col(thresholds))
+    pred = jnp.where(pred == 0, 1.0, pred)
+    return jnp.sum(pred * col(alphas), axis=0)
+
+
 def haar_stage_scores(ii_flat, base, sid, inv_norm, offsets, weights,
                       thresholds, polarity, alphas):
     """One cascade stage's AdaBoost score per window, (n,) f32: corner-tap
     gathers into the flattened frame integral image, stump vote, stage
-    reduce.
+    reduce.  Survivor stages run this, since their windows are an
+    arbitrary compacted subset; stage 0, which sees the whole scan grid,
+    reads the same taps as strided slices (:func:`grid_stage_scores`).
 
     ii_flat:    (L,) flattened zero-padded frame integral image.
     base:       (n,) int32 window top-left flat index, y * (W + 1) + x.
@@ -502,8 +532,7 @@ def haar_stage_scores(ii_flat, base, sid, inv_norm, offsets, weights,
     weights:    (sz, K) f32 corner weights (0 in padded slots).
     thresholds, polarity, alphas: (sz,) decision-stump parameters.
 
-    XLA on every backend: Mosaic cannot lower flat-table gathers, so a
-    Pallas TPU kernel would need the taps as strided slices instead.
+    XLA on every backend: Mosaic cannot lower flat-table gathers.
     """
     # one stump at a time, windows on the minor axis: a whole-stage
     # (n, sz, K) tap tensor costs 264 int32 per window, and on the TPU its
@@ -516,10 +545,127 @@ def haar_stage_scores(ii_flat, base, sid, inv_norm, offsets, weights,
         return jnp.sum(vals * w_k[:, None], axis=0)
 
     resp = jax.lax.map(stump, (jnp.transpose(offsets, (1, 2, 0)), weights))
-    resp = resp * inv_norm[None, :]                      # (sz, n)
-    pred = polarity[:, None] * jnp.sign(resp - thresholds[:, None])
-    pred = jnp.where(pred == 0, 1.0, pred)
-    return jnp.sum(pred * alphas[:, None], axis=0)
+    return _stage_vote(resp, inv_norm, thresholds, polarity, alphas)
+
+
+def _grid_phases(ii, rects):
+    """Every stride phase of ``ii`` that the grid's scales read, as one
+    (P, hc, wc) table, and each step's first phase: phase a * sx + b of
+    step (sy, sx) holds ii[a::sy, b::sx].  A tap read every sy rows and sx
+    columns is then a unit-stride window of one phase (the TPU lowers a
+    strided slice to a gather).  The phases are zero-padded so that a
+    window of the largest scale's grid fits at every tap."""
+    h, w = ii.shape
+    steps = sorted({rect[4:] for rect in rects})
+    hc = max(-(-h // sy) for sy, _sx in steps) + max(r[2] for r in rects)
+    wc = max(-(-w // sx) for _sy, sx in steps) + max(r[3] for r in rects)
+    tabs, first = [], {}
+    for sy, sx in steps:
+        first[sy, sx] = sum(t.shape[0] for t in tabs)
+        padded = jnp.pad(ii, ((0, hc * sy - h), (0, wc * sx - w)))
+        tabs.append(padded.reshape(hc, sy, wc, sx).transpose(1, 3, 0, 2)
+                    .reshape(sy * sx, hc, wc))
+    return jnp.concatenate(tabs), first
+
+
+def _phase_starts(rect, dy, dx, first) -> np.ndarray:
+    """Each tap's (phase, row, col) start in :func:`_grid_phases`'s table
+    for one scale's grid, from its offsets (dy, dx) to the window's
+    top-left corner: the shape of ``dy`` plus a trailing 3."""
+    y0, x0, _ny, _nx, sy, sx = rect
+    r, c = y0 + np.asarray(dy), x0 + np.asarray(dx)
+    phase = first[sy, sx] + (r % sy) * sx + c % sx
+    return np.stack([phase, r // sy, c // sx], axis=-1).astype(np.int32)
+
+
+def _grid_windows(tab, starts, ny, nx):
+    """(K, ny, nx): the windows of ``tab`` at the K ``starts``."""
+    return jnp.stack([jax.lax.dynamic_slice(tab, tuple(starts[k]),
+                                            (1, ny, nx))[0]
+                      for k in range(starts.shape[0])])
+
+
+def _by_scale(rects, flat, shape):
+    """(n,) in grid order -> (n_scales, *shape), each scale's (ny, nx)
+    rectangle zero-padded to ``shape``; :func:`_to_grid` inverts it."""
+    out, o = [], 0
+    for rect in rects:
+        ny, nx = rect[2:4]
+        plane = flat[o:o + ny * nx].reshape(ny, nx)
+        out.append(jnp.pad(plane, ((0, shape[0] - ny), (0, shape[1] - nx))))
+        o += ny * nx
+    return jnp.stack(out)
+
+
+def _to_grid(rects, planes):
+    """(n_scales, ny_max, nx_max) -> (n,) in grid order."""
+    return jnp.concatenate([planes[s, :rect[2], :rect[3]].reshape(-1)
+                            for s, rect in enumerate(rects)])
+
+
+def grid_stage_scores(ii, inv_norm, rects, offsets, weights, thresholds,
+                      polarity, alphas):
+    """One cascade stage's AdaBoost score for every window of a whole scan
+    grid, (n,) f32 in grid order: the same taps, sums and vote as
+    :func:`haar_stage_scores`, with each tap of a scale read as a strided
+    slice of the integral image instead of gathered.
+
+    ii:         (h+1, w+1) zero-padded frame integral image.
+    inv_norm:   (n,) f32 per-window 1 / (sd * area), grid order.
+    rects:      per scale (y0, x0, ny, nx, sy, sx) (``ScanGrid.rects``).
+    offsets:    (n_scales, sz, K) corner taps per scale, dy * (w + 1) + dx,
+                as NumPy: static, since they place the slices.
+    weights:    (sz, K) f32 corner weights (0 in padded slots).
+    thresholds, polarity, alphas: (sz,) decision-stump parameters.
+
+    Every scale is computed on the largest scale's (ny, nx) rectangle, so
+    one stump body serves them all; the padding is dropped at the end.
+    """
+    dy, dx = np.divmod(np.asarray(offsets), ii.shape[1])
+    n_scales, sz, k = dy.shape
+    shape = (max(r[2] for r in rects), max(r[3] for r in rects))
+    tab, first = _grid_phases(ii, rects)
+    starts = np.stack([_phase_starts(rect, dy[s], dx[s], first)
+                       for s, rect in enumerate(rects)], axis=1)
+
+    # one (stump, scale) at a time, as haar_stage_scores goes stump by stump
+    def stump(args):
+        st, w_k = args                                   # (K, 3), (K,)
+        vals = _grid_windows(tab, st, *shape)
+        return jnp.sum(vals * w_k[:, None, None], axis=0)
+
+    resp = jax.lax.map(stump, (jnp.asarray(starts.reshape(-1, k, 3)),
+                               jnp.repeat(weights, n_scales, axis=0)))
+    resp = resp.reshape((sz, n_scales) + shape)
+    inv = _by_scale(rects, inv_norm, shape)
+    return _to_grid(rects, _stage_vote(resp, inv, thresholds, polarity,
+                                       alphas))
+
+
+def grid_inv_norm(ii, ii2, rects, scales, areas):
+    """Every scan window's normalizer 1 / (sd * area), (n,) f32 in grid
+    order, from the window-sum corners of the frame's integral image ``ii``
+    and of its square ``ii2``, each read as a strided slice (a window of
+    :func:`_grid_phases`).  ``areas`` (n_scales,) holds each scale's
+    win^2."""
+    norm_w = jnp.asarray(_NORM_W)[:, None, None]
+    shape = (max(r[2] for r in rects), max(r[3] for r in rects))
+    tab, first = _grid_phases(ii, rects)
+    tab2, _ = _grid_phases(ii2, rects)
+    starts = np.stack([
+        _phase_starts(rect, (win, 0, win, 0), (win, win, 0, 0), first)
+        for rect, win in zip(rects, scales)])            # (n_scales, 4, 3)
+
+    def window_sums(st):
+        return tuple(jnp.sum(_grid_windows(t, st, *shape) * norm_w, axis=0)
+                     for t in (tab, tab2))
+
+    s1, s2 = jax.lax.map(window_sums, jnp.asarray(starts))
+    area = areas[:, None, None]
+    mu = s1 / area
+    var = s2 / area - mu * mu
+    sd = jnp.sqrt(jnp.maximum(var, 1e-6))
+    return _to_grid(rects, 1.0 / (sd * area))
 
 
 class FusedDetector:
@@ -529,10 +675,12 @@ class FusedDetector:
     the squared frame, for variance normalization) — computed by the
     streaming Pallas kernel on TPU (kernels/integral_image) or the jnp
     oracle elsewhere.  Every scanning window at every pyramid scale is then
-    evaluated by gathering <= 8 corners per weak classifier out of that one
-    table (:func:`haar_stage_scores`), replacing the seed's ~400x data
-    amplification (25,853 materialized 20x20 windows per 176x144 frame)
-    with lookups.  The stage loop runs through
+    evaluated from <= 8 corners per weak classifier out of that one table,
+    replacing the seed's ~400x data amplification (25,853 materialized
+    20x20 windows per 176x144 frame) with lookups: stage 0 reads each
+    corner of the whole grid as a strided slice
+    (:func:`grid_stage_scores`), the survivor stages gather theirs
+    (:func:`haar_stage_scores`).  The stage loop runs through
     ``core.cascade.compacting_cascade``: after :meth:`calibrate`, stage i
     computes only on a capacity-bounded survivor prefix, so the paper's
     "86% fewer invocations" saves real FLOPs under static shapes.
@@ -574,39 +722,43 @@ class FusedDetector:
         # gain, since the gathers themselves depend on the frame).
         consts = tuple(jnp.asarray(a) for a in (
             self.grid.bases, self.grid.scale_id, t.offsets, t.weights,
-            t.thresholds, t.polarity, t.alphas, t.norm_offsets, t.areas))
+            t.thresholds, t.polarity, t.alphas, t.areas))
         bounds, o = [], 0
         for sz in t.stage_sizes:
             bounds.append((o, o + sz))
             o += sz
         stage_thr = [float(v) + self.strictness for v in t.stage_thresholds]
         use_pallas, interpret = self.use_pallas, self.interpret
+        # stage 0 always sees the whole grid in grid order (its capacity is
+        # the window count), so it reads its taps as slices of the integral
+        # image, placed by its offsets as static NumPy
+        rects, scales, n_windows = (self.grid.rects, self.grid.scales,
+                                    self.n_windows)
+        lo0, hi0 = bounds[0]
+        offsets0 = t.offsets[:, lo0:hi0]
 
         def apply(frames, bases, sids, offsets, weights, thr, pol, al,
-                  n_off, areas):
-            norm_w = jnp.asarray(_NORM_W)
+                  areas):
             # the scale-id table rides in as a jit *argument* (NOTE above),
             # so its in-bounds promise is data-dependent; clamp once — a
             # no-op for real grids — to make the per-scale lookups below
             # statically guarded
             sids = jnp.clip(sids, 0, areas.shape[0] - 1)
 
-            def one_frame(iif, ii2f):
-                nidx = bases[:, None] + n_off[sids]
-                s1 = jnp.sum(jnp.take(iif, nidx.reshape(-1))
-                             .reshape(nidx.shape) * norm_w, -1)
-                s2 = jnp.sum(jnp.take(ii2f, nidx.reshape(-1))
-                             .reshape(nidx.shape) * norm_w, -1)
-                area = areas[sids]
-                mu = s1 / area
-                var = s2 / area - mu * mu
-                sd = jnp.sqrt(jnp.maximum(var, 1e-6))
-                inv = 1.0 / (sd * area)
+            def one_frame(ii, ii2):
+                iif = ii.reshape(-1)
+                inv = grid_inv_norm(ii, ii2, rects, scales, areas)
                 # a window is fully described by (base, scale id,
                 # normalizer) — this triple is what the compacting cascade
                 # carries and compacts.
                 items = jnp.stack([bases.astype(jnp.float32),
                                    sids.astype(jnp.float32), inv], axis=1)
+
+                def grid_fn(it):
+                    assert it.shape[0] == n_windows, it.shape
+                    return grid_stage_scores(ii, it[:, 2], rects, offsets0,
+                                             weights[lo0:hi0], thr[lo0:hi0],
+                                             pol[lo0:hi0], al[lo0:hi0])
 
                 def stage_fn(lo, hi):
                     def fn(it):
@@ -625,8 +777,8 @@ class FusedDetector:
                             pol[lo:hi], al[lo:hi])
                     return fn
 
-                stages = [CoreStage(stage_fn(lo, hi), stage_thr[si],
-                                    f"vj{si}")
+                stages = [CoreStage(grid_fn if si == 0 else stage_fn(lo, hi),
+                                    stage_thr[si], f"vj{si}")
                           for si, (lo, hi) in enumerate(bounds)]
                 res = compacting_cascade(stages, items, list(capacities))
                 return res.mask, res.n_survivors, res.dropped
@@ -637,8 +789,7 @@ class FusedDetector:
                                     interpret=interpret)
                 ii2 = frame_integral(frames * frames, use_pallas=use_pallas,
                                      interpret=interpret)
-            b = frames.shape[0]
-            return jax.vmap(one_frame)(ii.reshape(b, -1), ii2.reshape(b, -1))
+            return jax.vmap(one_frame)(ii, ii2)
 
         jitted = jax.jit(apply)
         # Traceable handle for callers that fuse the detector into a LARGER

@@ -6,7 +6,9 @@ A Pallas kernel that interpret mode accepts can still be refused by Mosaic
 (unaligned slices, unsupported gathers and reshapes); these cases catch
 that here.  Kernels meant to stay Pallas on the TPU must show up as a
 ``tpu_custom_call`` in the compiled HLO; the Haar front-end is XLA on every
-backend (camera/viola_jones.haar_stage_scores) and must not.
+backend (camera/viola_jones.haar_stage_scores) and must not.  Its stage 0,
+which scores the whole scan grid, reads its taps as slices and must compile
+with no gather under its ``vj0`` scope.
 
 The dispatch helpers ask ``jax.default_backend()``, which is the CPU here,
 so each case calls the TPU branch explicitly.  The topology is described
@@ -82,6 +84,30 @@ def test_haar_stage_fa_scan_compiles_on_xla(spec):
         spec((n_scales, sz, k), jnp.int32), spec((sz, k)),
         spec((sz,)), spec((sz,)), spec((sz,)))
     assert "tpu_custom_call" not in hlo
+
+
+def test_fused_detector_stage0_reads_no_gather(spec):
+    """A fleet dispatch's detector (16 frames of 144x176, the scan the
+    fleet runs): stage 0 reads slices of the integral image; the survivor
+    stages still gather."""
+    import numpy as np
+
+    from repro.camera.viola_jones import Cascade, FusedDetector, HaarFeature
+
+    z = np.load(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "tests", "data",
+        "toy_detector.npz"))
+    casc = Cascade(feats=[HaarFeature(*map(int, r)) for r in z["feats"]],
+                   thresholds=z["thresholds"], polarity=z["polarity"],
+                   alphas=z["alphas"],
+                   stage_sizes=[int(s) for s in z["stage_sizes"]],
+                   stage_thresholds=z["stage_thresholds"])
+    det = FusedDetector(casc, 144, 176, use_pallas=True)
+    hlo = _compiled_hlo(det.traceable_apply, spec((16, 144, 176)),
+                        *(spec(a.shape, a.dtype) for a in det.apply_consts))
+    gathers = [ln for ln in hlo.splitlines() if " gather(" in ln]
+    assert any("vj1" in ln for ln in gathers)
+    assert not [ln for ln in gathers if "vj0" in ln]
 
 
 @pytest.mark.parametrize("name", ["fa_nn_l1", "fa_nn_l2"])
