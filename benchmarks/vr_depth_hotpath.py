@@ -8,11 +8,11 @@ and the claim is the *ratio*):
 
   oracle — the seed ``bssa_depth_ref`` dataflow, kept verbatim: a Python
            loop over the rig's pairs, each materializing D+1 full-frame
-           SAD maps (one integral image per disparity hypothesis)
+           SAD maps (one separable box sum per disparity hypothesis)
            eagerly, then the scan-refine.  Timed warm (per-op caches
            populated) — the steady-state number, not first-call compile;
-  fused  — ``VRRigExecutor``: the chunked cost-volume integral image +
-           vectorized argmin, splat, ``refine_grid``, slice — one vmapped
+  fused  — ``VRRigExecutor``: the chunked cost volume with a running
+           min, splat, ``refine_grid``, slice — one vmapped
            jit region per rig frame on a single device;
   rig    — the same executor with the pairs split over the host's
            devices (the paper's 8-parallel-FPGA rig shape); on the CPU it

@@ -14,8 +14,10 @@ Pipeline per camera pair (Fig. 10/12):
    f32 throughout — the paper found >=32-bit float necessary for quality.
 4. **Slice** — sample the refined grid back at pixel coordinates.
 
-The blur kernel is the perf-critical unit: kernels/bilateral_blur holds
-the Pallas TPU version, and :func:`bssa_depth` refines through it (via
+The rough disparity's 5x5 SADs are separable box sums of shifted adds,
+exact in float32 for 8-bit views at any frame size.  The blur kernel is
+the perf-critical unit: kernels/bilateral_blur holds the Pallas TPU
+version, and :func:`bssa_depth` refines through it (via
 ``ops.refine_grid`` backend dispatch).  This module keeps the jnp oracles
 (:func:`rough_disparity_ref`, :func:`refine`, :func:`bssa_depth_ref`) and
 the quality harness (MS-SSIM vs grid size, Fig. 11b); the rig-scale
@@ -42,58 +44,58 @@ import jax.numpy as jnp
 # is recovered exactly (pinned by the shifted-pair property test).
 
 
+def _box_sad(left: jax.Array, rs: jax.Array, patch: int) -> jax.Array:
+    """5x5 (``patch`` x ``patch``) SAD of one hypothesis, exact association:
+    |left - rs| edge-padded by ``patch // 2``, then ``patch`` row sums, then
+    ``patch`` column sums, each added left to right in float32."""
+    h, w = left.shape
+    dp = jnp.pad(jnp.abs(left - rs), patch // 2, mode="edge")
+    rows = dp[0:h]
+    for i in range(1, patch):
+        rows = rows + dp[i:i + h]
+    sad = rows[:, 0:w]
+    for j in range(1, patch):
+        sad = sad + rows[:, j:j + w]
+    return sad
+
+
 def rough_disparity(left: jax.Array, right: jax.Array, max_disp: int = 16,
-                    patch: int = 5, *, hypothesis_chunk: int = 8,
-                    use_pallas: bool | None = None,
-                    interpret: bool = False) -> jax.Array:
+                    patch: int = 5, *, hypothesis_chunk: int = 8) -> jax.Array:
     """Winner-take-all SAD block matching.  (h, w) f32 -> (h, w) f32.
 
-    Fused cost-volume formulation: shifted right views are gathered as one
-    indexed load, their |left - right_d| maps stacked and pushed through a
-    single batched padded integral image (the same unit VJ uses —
-    kernels/integral_image when ``use_pallas``), and the winning hypothesis
-    taken by a vectorized argmin.  The hypothesis axis is blocked into
-    ``hypothesis_chunk``-sized chunks scanned with a running min so the
-    working set stays cache-resident (chunk >= D+1 degenerates to the pure
-    one-shot stack).  Numerically identical to the seed Python loop
-    (:func:`rough_disparity_ref`): same cumsum association per hypothesis,
-    same edge replication, same first-wins tie-breaking.
+    Each hypothesis' patch SAD is a separable box sum of shifted adds
+    (:func:`_box_sad`): a few additions per pixel, every partial sum no
+    larger than one patch's, so float32 keeps the SADs of 8-bit views
+    exact at any frame size (a whole-frame summed-area table reaches the
+    millions at 3840x2160 and rounds a 1/256 step away).  The hypothesis
+    axis is scanned in ``hypothesis_chunk``-sized chunks: one dynamic slice
+    of the left-edge-padded right view per chunk, a static slice of it per
+    hypothesis, and a strict running min, so the first least hypothesis
+    wins.  Equal, bit for bit, to :func:`rough_disparity_ref` and to the
+    plain reference's ``rough``.
     """
-    from repro.camera.integral import frame_integral
-
-    if use_pallas is None:
-        use_pallas = interpret or jax.default_backend() == "tpu"
     h, w = left.shape
-    pad = patch // 2
     n_hyp = max_disp + 1
     chunk = min(hypothesis_chunk, n_hyp)
     n_chunks = -(-n_hyp // chunk)
-
-    def sad_chunk(ds):
-        # shifted right views as one gather: rs[d, y, x] = right[y, max(x-d, 0)]
-        # (edge columns replicate, matching the seed's roll + first-column fill)
-        # two-sided clip (d >= 0 makes the upper bound vacuous, but the
-        # gather below is PROMISE_IN_BOUNDS — guard both sides statically)
-        xs = jnp.clip(jnp.arange(w)[None, :] - ds[:, None], 0, w - 1)
-        rstack = jnp.moveaxis(right[:, xs], 1, 0)          # (chunk, h, w)
-        diff = jnp.abs(left[None] - rstack)
-        dp = jnp.pad(diff, ((0, 0), (pad, pad), (pad, pad)), mode="edge")
-        ii = frame_integral(dp, use_pallas=use_pallas, interpret=interpret)
-        sad = (ii[:, patch:, patch:] - ii[:, :-patch, patch:]
-               - ii[:, patch:, :-patch] + ii[:, :-patch, :-patch])
-        return sad[:, :h, :w]
+    span = n_chunks * chunk
+    # rpad[:, j] = right[:, max(j - (span - 1), 0)]: shifting by d is the
+    # window starting at span - 1 - d (edge columns replicate)
+    rpad = jnp.pad(right, ((0, 0), (span - 1, 0)), mode="edge")
 
     def body(carry, c):
         best, bestd = carry
-        # clamp the ragged tail to d = max_disp: the duplicates produce
-        # identical SADs and the strict running min keeps the first winner
-        ds = jnp.minimum(c * chunk + jnp.arange(chunk), max_disp)
-        sad = sad_chunk(ds)
-        cmin = jnp.min(sad, axis=0)
-        carg = jnp.argmin(sad, axis=0)
-        better = cmin < best
-        return (jnp.where(better, cmin, best),
-                jnp.where(better, ds[carg], bestd)), None
+        d0 = c * chunk
+        block = jax.lax.dynamic_slice_in_dim(rpad, span - chunk - d0,
+                                             w + chunk - 1, axis=1)
+        for k in range(chunk):
+            rs = block[:, chunk - 1 - k:chunk - 1 - k + w]
+            sad = _box_sad(left, rs, patch)
+            # the ragged tail's hypotheses past max_disp never win
+            better = (sad < best) & (d0 + k <= max_disp)
+            best = jnp.where(better, sad, best)
+            bestd = jnp.where(better, d0 + k, bestd)
+        return (best, bestd), None
 
     init = (jnp.full((h, w), jnp.inf), jnp.zeros((h, w), jnp.int32))
     (_, bestd), _ = jax.lax.scan(body, init, jnp.arange(n_chunks))
@@ -102,23 +104,23 @@ def rough_disparity(left: jax.Array, right: jax.Array, max_disp: int = 16,
 
 def rough_disparity_ref(left: jax.Array, right: jax.Array, max_disp: int = 16,
                         patch: int = 5) -> jax.Array:
-    """Seed per-hypothesis Python loop — the golden oracle (and the
-    benchmark baseline): materializes D+1 full-frame SAD maps, one integral
-    image each."""
+    """Per-hypothesis Python loop, the golden oracle: materializes D+1
+    full-frame SAD maps, each five row sums then five column sums of the
+    edge-padded |left - right shifted d|, and takes the first argmin."""
     h, w = left.shape
     pad = patch // 2
     costs = []
     for d in range(max_disp + 1):
         rs = jnp.roll(right, d, axis=1)
         rs = rs.at[:, :d].set(right[:, :1] if d else rs[:, :d])
-        diff = jnp.abs(left - rs)
-        dp = jnp.pad(diff, pad, mode="edge")
-        # box filter via cumsum (integral image trick — same unit as VJ!)
-        ii = jnp.cumsum(jnp.cumsum(dp, axis=0), axis=1)
-        ii = jnp.pad(ii, ((1, 0), (1, 0)))
-        sad = (ii[patch:, patch:] - ii[:-patch, patch:]
-               - ii[patch:, :-patch] + ii[:-patch, :-patch])
-        costs.append(sad[:h, :w])
+        dp = jnp.pad(jnp.abs(left - rs), pad, mode="edge")
+        rows = dp[0:h]
+        for i in range(1, patch):
+            rows = rows + dp[i:i + h]
+        sad = rows[:, 0:w]
+        for j in range(1, patch):
+            sad = sad + rows[:, j:j + w]
+        costs.append(sad)
     cost = jnp.stack(costs)                      # (D+1, h, w)
     return jnp.argmin(cost, axis=0).astype(jnp.float32)
 
@@ -248,22 +250,29 @@ def slice_grid(grid_val: jax.Array, grid_wt: jax.Array, img: jax.Array,
 def bssa_depth(left: jax.Array, right: jax.Array, spec: GridSpec,
                max_disp: int = 16, n_iters: int = 8, *,
                use_pallas: bool | None = None, interpret: bool = False):
-    """Full BSSA: fused rough disparity -> splat -> refine_grid -> slice.
+    """Full BSSA: rough disparity -> splat -> refine_grid -> slice.
 
     Refinement runs through kernels/bilateral_blur's ``refine_grid``
     (backend dispatch: the Pallas stencil on TPU, the blur_121 oracle math
     elsewhere — identical semantics either way, pinned in
-    tests/test_kernels.py).  The end-to-end seed path survives as
-    :func:`bssa_depth_ref`, the golden oracle.
+    tests/test_kernels.py); ``use_pallas``/``interpret`` steer that
+    dispatch.  Each of the four layers is a ``jax.named_scope`` (``rough``,
+    ``splat``, ``refine``, ``slice``) around the whole layer, its loops
+    included, so a device trace gives every operation to its layer.  The
+    end-to-end seed path survives as :func:`bssa_depth_ref`, the golden
+    oracle.
     """
     from repro.kernels.bilateral_blur.ops import refine_grid
 
-    rough = rough_disparity(left, right, max_disp, use_pallas=use_pallas,
-                            interpret=interpret)
-    gv, gw = splat(left, rough, spec)
-    gv, gw = refine_grid(gv, gw, n_iters=n_iters, use_pallas=use_pallas,
-                         interpret=interpret)
-    return slice_grid(gv, gw, left, spec)
+    with jax.named_scope("rough"):
+        rough = rough_disparity(left, right, max_disp)
+    with jax.named_scope("splat"):
+        gv, gw = splat(left, rough, spec)
+    with jax.named_scope("refine"):
+        gv, gw = refine_grid(gv, gw, n_iters=n_iters, use_pallas=use_pallas,
+                             interpret=interpret)
+    with jax.named_scope("slice"):
+        return slice_grid(gv, gw, left, spec)
 
 
 def bssa_depth_ref(left: jax.Array, right: jax.Array, spec: GridSpec,

@@ -19,6 +19,7 @@ they do.  See DESIGN.md §5 and EXPERIMENTS.md for the argument.
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import numpy as np
 
@@ -284,9 +285,14 @@ class VRRigExecutor:
     over the devices (one per device when they fit, else a zero-padded
     even split, e.g. 2 per chip for the 8-pair rig on a 4-chip host) —
     the software analogue of the
-    paper's rig of 8 parallel per-pair FPGAs.  The seed per-pair Python
-    loop over ``bssa_depth_ref`` is the oracle the benchmark (benchmarks/vr_depth_hotpath.py) and the
-    parity tests measure against.
+    paper's rig of 8 parallel per-pair FPGAs.  The rough disparity's 5x5
+    SADs are separable box sums, exact for 8-bit views at 3840x2160; the
+    depth step names its layers ``rough``, ``splat``, ``refine`` and
+    ``slice`` (``camera.bssa.bssa_depth``).  With a ``telemetry``, each
+    ``depth_maps`` call is one ``dispatch``/``vr_depth`` span that lasts
+    until the depth maps are ready.  The per-pair Python loop over
+    ``bssa_depth_ref`` is the oracle the benchmark
+    (benchmarks/vr_depth_hotpath.py) and the parity tests measure against.
     """
 
     def __init__(self, spec, max_disp: int = 32, n_iters: int = 8,
@@ -345,19 +351,26 @@ class VRRigExecutor:
         import jax
         import jax.numpy as jnp
 
-        groups = _device_groups(lefts.shape[0], jax.local_device_count())
-        if self._depth_pmap is not None and groups:
-            out = _ungroup(self._depth_pmap(_group(lefts, groups),
-                                            _group(rights, groups)),
-                           lefts.shape[0])
-        else:
-            out = self._depth(lefts, rights)
-        if self._tel_on:
-            depths, aux = out
+        def dispatch():
+            groups = _device_groups(lefts.shape[0], jax.local_device_count())
+            if self._depth_pmap is not None and groups:
+                return _ungroup(self._depth_pmap(_group(lefts, groups),
+                                                 _group(rights, groups)),
+                                lefts.shape[0])
+            return self._depth(lefts, rights)
+
+        if not self._tel_on:
+            return dispatch()
+        # the span lasts until the depth maps are ready, so the step's
+        # device operations begin inside it
+        with self.telemetry.span("dispatch", "vr_depth",
+                                 t=time.perf_counter(),
+                                 pairs=int(lefts.shape[0])):
+            depths, aux = dispatch()
+            depths.block_until_ready()
             self.telemetry.counters.add("vr.pairs",
                                         jnp.sum(aux["tel_pairs"]))
-            return depths
-        return out
+        return depths
 
     def panorama(self, lefts, rights, depths):
         """(left_pano, right_pano) from per-pair views + depth maps."""

@@ -270,40 +270,86 @@ class TestBSSA:
         assert ms_ssim(a, a) > 0.99
 
 
+def _views8(h, w, max_disp, seed):
+    """A seeded stereo pair at 8 bits per pixel (k / 256), as the rig's
+    cameras deliver it."""
+    left, right, _ = stereo_pair(h=h, w=w, max_disp=max_disp, seed=seed)
+    q = lambda v: jnp.asarray(np.minimum(np.floor(v * 256), 255) / 256,
+                              jnp.float32)
+    return q(left), q(right)
+
+
+def _summed_area_winners(left, right, max_disp, patch=5):
+    """The cost volume as it was once computed: each hypothesis' SAD from
+    four corners of one float32 summed-area table of the whole padded
+    frame."""
+    h, w = left.shape
+    costs = []
+    for d in range(max_disp + 1):
+        xs = jnp.clip(jnp.arange(w) - d, 0, w - 1)
+        dp = jnp.pad(jnp.abs(left - right[:, xs]), patch // 2, mode="edge")
+        ii = jnp.pad(jnp.cumsum(jnp.cumsum(dp, 0), 1), ((1, 0), (1, 0)))
+        costs.append(ii[patch:, patch:] - ii[:-patch, patch:]
+                     - ii[patch:, :-patch] + ii[:-patch, :-patch])
+    return np.asarray(jnp.argmin(jnp.stack(costs), axis=0))
+
+
+def _reference_winners(left, right, max_disp):
+    from bench.reference import bssa as plain
+
+    return np.asarray(jax.jit(plain.rough, static_argnums=2)(left, right,
+                                                             max_disp))
+
+
 class TestBSSAFusedParity:
-    """The fused cost-volume path vs the seed loop oracles (PR acceptance:
-    same argmin disparities up to fp-borderline ties, depth within tol)."""
+    """The chunked cost volume and the end-to-end depth step against the
+    plain reference (bench/reference/bssa.py) and the seed loop oracles:
+    the same winners bit for bit, the depth within float32 rounding."""
 
     def test_rough_fused_equals_seed_loop(self):
-        left, right, _ = stereo_pair(h=72, w=96, seed=4)
-        a = np.asarray(rough_disparity(jnp.asarray(left), jnp.asarray(right), 12))
-        b = np.asarray(rough_disparity_ref(jnp.asarray(left), jnp.asarray(right), 12))
-        assert (a == b).mean() >= 0.999
+        left, right = _views8(72, 96, 12, seed=4)
+        a = np.asarray(rough_disparity(left, right, 12))
+        b = np.asarray(rough_disparity_ref(left, right, 12))
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(a, _reference_winners(left, right, 12))
 
     @pytest.mark.parametrize("chunk", [1, 4, 64])
     def test_rough_chunk_sizes_agree(self, chunk):
-        """chunk=1 (pure running-min scan) through chunk>=D+1 (the pure
-        one-shot stack) are the same computation."""
-        left, right, _ = stereo_pair(h=48, w=64, seed=5)
-        l, r = jnp.asarray(left), jnp.asarray(right)
-        a = np.asarray(rough_disparity(l, r, 12, hypothesis_chunk=chunk))
-        b = np.asarray(rough_disparity_ref(l, r, 12))
-        assert (a == b).mean() >= 0.999
+        """chunk=1 (one hypothesis per scan step) through chunk>=D+1 (one
+        step, a ragged tail of hypotheses past D that never win) give the
+        reference's winners."""
+        left, right = _views8(48, 64, 12, seed=5)
+        a = np.asarray(rough_disparity(left, right, 12,
+                                       hypothesis_chunk=chunk))
+        np.testing.assert_array_equal(a, _reference_winners(left, right, 12))
 
     def test_rough_pallas_integral_matches(self):
-        """interpret=True routes the cost-volume integral through the
-        Pallas streaming kernel — same winners up to fp-borderline ties
-        (the blocked integral carries a ~1e-3 association tolerance, so the
-        pair must have well-separated SAD minima: iid noise, constant
-        shift; smooth low-contrast regions would tie)."""
-        rng = np.random.default_rng(7)
-        full = rng.random((40, 60), np.float32)
-        left = jnp.asarray(full[:, :48])
-        right = jnp.asarray(full[:, 3:51])     # right[x] = left[x+3]
-        a = np.asarray(rough_disparity(left, right, 8, interpret=True))
-        b = np.asarray(rough_disparity_ref(left, right, 8))
-        inner = (a == b)[2:-2, 10:-10]         # clamped borders can tie
-        assert inner.mean() >= 0.99
+        """Where the integral kernel's whole-frame table flips winners, the
+        separable sums do not: on a 256x3840 strip of 8-bit views the
+        table's float32 prefix sums pass 2**16 and round away 1/256 steps
+        (986 winners of 983,040 differ from the reference on the CPU),
+        while rough_disparity equals the reference at every pixel."""
+        left, right = _views8(256, 3840, 6, seed=(5, 256))
+        want = _reference_winners(left, right, 8)
+        assert (_summed_area_winners(left, right, 8) != want).sum() > 100
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(rough_disparity, static_argnums=2)(
+                left, right, 8)), want)
+
+    def test_depth_maps_agree_with_the_plain_reference(self):
+        """VRRigExecutor's depth, 8-bit views, full camera width: the same
+        winners, so only the blur kernel's and the slice's float32
+        rounding separate it from the reference's depth."""
+        from bench.reference import bssa as plain
+        from repro.camera.pipelines import VRRigExecutor
+
+        left, right = _views8(24, 3840, 6, seed=9)
+        ex = VRRigExecutor(GridSpec(sigma_spatial=16), max_disp=8,
+                           n_iters=2)
+        got = np.asarray(ex.depth_maps(left[None], right[None]))[0]
+        want = np.asarray(plain.depth(left, right, max_disp=8, n_iters=2,
+                                      sigma_spatial=16, sigma_range=16.0))
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
 
     def test_bssa_depth_fused_matches_oracle(self):
         left, right, _ = stereo_pair(h=64, w=80, seed=6)
