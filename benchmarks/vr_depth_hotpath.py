@@ -94,7 +94,7 @@ def rows(n_oracle_pairs: int = 2, smoke: bool = False):
 
     from repro.camera.bssa import (
         GridSpec, bssa_depth_ref, refine, rough_disparity,
-        rough_disparity_ref, slice_grid, splat)
+        rough_disparity_ref, slice_grid, slice_grid_ref, splat)
     from repro.camera.pipelines import VR_FPS_TARGET, VRRigExecutor
     from repro.kernels.bilateral_blur.ops import refine_grid
 
@@ -155,7 +155,7 @@ def rows(n_oracle_pairs: int = 2, smoke: bool = False):
     t_os, (ogv, ogw) = _timed(splat, l0, o_rough, spec)
     t_orf, (ogv, ogw) = _timed(functools.partial(refine, n_iters=N_ITERS),
                                ogv, ogw)
-    t_osl, _ = _timed(slice_grid, ogv, ogw, l0, spec)
+    t_osl, _ = _timed(slice_grid_ref, ogv, ogw, l0, spec)
     oracle_blocks = dict(rough=t_or, splat=t_os, refine=t_orf, slice=t_osl)
 
     # ---- rows ---------------------------------------------------------------

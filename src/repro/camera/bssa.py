@@ -19,9 +19,10 @@ exact in float32 for 8-bit views at any frame size.  The blur kernel is
 the perf-critical unit: kernels/bilateral_blur holds the Pallas TPU
 version, and :func:`bssa_depth` refines through it (via
 ``ops.refine_grid`` backend dispatch).  This module keeps the jnp oracles
-(:func:`rough_disparity_ref`, :func:`refine`, :func:`bssa_depth_ref`) and
-the quality harness (MS-SSIM vs grid size, Fig. 11b); the rig-scale
-batched executor is ``camera.pipelines.VRRigExecutor``.
+(:func:`rough_disparity_ref`, :func:`refine`, :func:`slice_grid_ref`,
+:func:`bssa_depth_ref`) and the quality harness (MS-SSIM vs grid size,
+Fig. 11b); the rig-scale batched executor is
+``camera.pipelines.VRRigExecutor``.
 """
 
 from __future__ import annotations
@@ -211,9 +212,76 @@ def refine(grid_val: jax.Array, grid_wt: jax.Array, n_iters: int = 8):
     return v, w
 
 
+def _lattice(n: int, s: int, g: int):
+    """Lower corner and weight of the upper one for pixel positions
+    0..n-1 on an axis of ``g`` vertices ``s`` pixels apart, clipped as
+    :func:`slice_grid_ref` clips them.  Static: float32 numpy."""
+    c = np.arange(n, dtype=np.float32) / np.float32(s)
+    i0 = np.clip(np.floor(c), 0, g - 2)
+    return i0, np.clip(c - i0, 0, 1)
+
+
 def slice_grid(grid_val: jax.Array, grid_wt: jax.Array, img: jax.Array,
                spec: GridSpec) -> jax.Array:
-    """Trilinear sampling of the refined grid at each pixel's coordinates."""
+    """Trilinear sampling of the refined grid at each pixel's coordinates,
+    with no gather.
+
+    Only the range corner depends on the data: a pixel's spatial corners
+    and weights are fixed by its position.  So the x direction is a
+    float32 (``HIGHEST``) matmul of each grid's (row, bin) planes against a
+    static two-tap (gx, w) matrix; the y direction splits the image into
+    blocks of s rows (edge-padded to whole blocks, cropped after), and
+    block j blends grid rows j and j + 1; the range picks bins r0 and
+    r0 + 1, computed and clipped as :func:`slice_grid_ref` does, by a
+    select chain over the gr - 1 values of r0.  Within float32 rounding of
+    :func:`slice_grid_ref` (tests/test_camera_pipeline.py).
+    """
+    h, w = img.shape
+    gy, gx, gr = grid_val.shape
+    s = spec.sigma_spatial
+    nb = -(-h // s)                      # row blocks; rows y0 = j, j + 1
+    x0, fx = _lattice(w, s, gx)
+    taps = jnp.arange(gx)[:, None]
+    wx = jnp.where(taps == x0, 1 - fx, 0) + jnp.where(taps == x0 + 1, fx, 0)
+    _, fy = _lattice(nb * s, s, gy)
+    fy = fy.reshape(nb, s, 1)
+
+    # the barrier keeps the blocks (nb, s, w) the shape of the fusion
+    # below: fused with the reshape back to (h, w), XLA:TPU materializes
+    # every grid row's broadcast over its s pixel rows instead
+    blocks = jax.lax.optimization_barrier(
+        jnp.pad(img, ((0, nb * s - h), (0, 0)), mode="edge")
+        .reshape(nb, s, w))
+    cr = blocks * 255.0 / spec.sigma_range
+    r0 = jnp.clip(jnp.floor(cr), 0, gr - 2)
+    fr = jnp.clip(cr - r0, 0, 1)
+    hits = [(k, r0 == k) for k in range(1, gr - 1)]
+
+    def sample(grid):
+        # (gy, gx, gr) -> (gy, gr, w): each row and bin interpolated in x
+        xs = jnp.einsum("yxr,xw->yrw", grid, wx,
+                        precision=jax.lax.Precision.HIGHEST)
+
+        def at(dy, k):                           # (nb, 1, w): rows j + dy
+            return xs[dy:dy + nb, None, k]
+
+        out = 0.0
+        for dy in (0, 1):
+            lo, hi = at(dy, 0), at(dy, 1)
+            for k, hit in hits:
+                lo = jnp.where(hit, at(dy, k), lo)
+                hi = jnp.where(hit, at(dy, k + 1), hi)
+            out = out + (fy if dy else 1 - fy) * ((1 - fr) * lo + fr * hi)
+        return out
+
+    out = sample(grid_val) / jnp.maximum(sample(grid_wt), 1e-6)
+    return out.reshape(nb * s, w)[:h]
+
+
+def slice_grid_ref(grid_val: jax.Array, grid_wt: jax.Array, img: jax.Array,
+                   spec: GridSpec) -> jax.Array:
+    """Trilinear sampling by 16 flat-table gathers (8 corners x 2 grids),
+    the golden oracle of :func:`slice_grid`."""
     h, w = img.shape
     gy, gx, gr = grid_val.shape
     cy, cx, cr = _grid_coords(img, spec)
@@ -282,7 +350,7 @@ def bssa_depth_ref(left: jax.Array, right: jax.Array, spec: GridSpec,
     rough = rough_disparity_ref(left, right, max_disp)
     gv, gw = splat(left, rough, spec)
     gv, gw = refine(gv, gw, n_iters)
-    return slice_grid(gv, gw, left, spec)
+    return slice_grid_ref(gv, gw, left, spec)
 
 
 # ---------------------------------------------------------------------------

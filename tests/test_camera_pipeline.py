@@ -9,7 +9,8 @@ from hypothesis_compat import given, settings, st
 
 from repro.camera.bssa import (
     GridSpec, _grid_coords, blur_121, bssa_depth, bssa_depth_ref, ms_ssim,
-    refine, rough_disparity, rough_disparity_ref, slice_grid, splat)
+    refine, rough_disparity, rough_disparity_ref, slice_grid, slice_grid_ref,
+    splat)
 from repro.camera.face_nn import (
     classification_error, forward_float, forward_lut, forward_quantized,
     make_sigmoid_lut, nn_power, train_face_nn)
@@ -268,6 +269,29 @@ class TestBSSA:
     def test_msssim_identity(self):
         a = jnp.asarray(np.random.default_rng(0).random((64, 64), np.float32))
         assert ms_ssim(a, a) > 0.99
+
+    @pytest.mark.parametrize("s", [8, 12, 16])
+    @pytest.mark.parametrize("whole", [True, False],
+                             ids=["whole_blocks", "ragged"])
+    def test_slice_matches_gather_oracle(self, s, whole):
+        """The gather-free slice against the 16-gather oracle, both jitted
+        and vmapped over a batch of 2: frames that are whole s x s blocks
+        and frames that are not, 8-bit intensities with 0 and 1 among them
+        and a few outside [0, 1], which clip to the end bins."""
+        h, w = (4 * s, 6 * s) if whole else (4 * s + 5, 6 * s - 3)
+        spec = GridSpec(sigma_spatial=s)
+        gy, gx, gr = spec.dims(h, w)
+        rng = np.random.default_rng(100 * s + h)
+        gv = rng.random((2, gy, gx, gr), np.float32) * 30
+        gw = rng.random((2, gy, gx, gr), np.float32) + 0.5
+        img = rng.integers(0, 256, (2, h, w)).astype(np.float32) / 255
+        img[:, 0, :6] = [0.0, 1.0, -0.2, 1.3, -3.0, 2.0]
+        args = [jnp.asarray(a) for a in (gv, gw, img)]
+        got, want = (np.asarray(jax.jit(jax.vmap(
+            lambda v, wt, im, f=f: f(v, wt, im, spec)))(*args))
+            for f in (slice_grid, slice_grid_ref))
+        assert got.shape == (2, h, w)
+        np.testing.assert_allclose(got, want, rtol=1e-5)
 
 
 def _views8(h, w, max_disp, seed):

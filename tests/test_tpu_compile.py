@@ -8,7 +8,8 @@ that here.  Kernels meant to stay Pallas on the TPU must show up as a
 ``tpu_custom_call`` in the compiled HLO; the Haar front-end is XLA on every
 backend (camera/viola_jones.haar_stage_scores) and must not.  Its stage 0,
 which scores the whole scan grid, reads its taps as slices and must compile
-with no gather under its ``vj0`` scope.
+with no gather under its ``vj0`` scope; so must the rig's slice under
+``slice``.
 
 The dispatch helpers ask ``jax.default_backend()``, which is the CPU here,
 so each case calls the TPU branch explicitly.  The topology is described
@@ -108,6 +109,32 @@ def test_fused_detector_stage0_reads_no_gather(spec):
     gathers = [ln for ln in hlo.splitlines() if " gather(" in ln]
     assert any("vj1" in ln for ln in gathers)
     assert not [ln for ln in gathers if "vj0" in ln]
+
+
+def test_rig_slice_reads_no_gather(spec):
+    """The rig pair's slice at 3840x2160 (grids of 136 x 241 x 17, vmapped
+    over one pair, as ``VRRigExecutor.depth_maps`` runs it): the corners
+    are static slices of the grid and a select over its bins, so no
+    gather carries the ``slice`` scope.  Nor is a grid row broadcast over
+    its 16 pixel rows once per bin in memory: that took 2.2 GB of
+    temporaries, and with the image's row blocks the slice takes under
+    0.1 GB."""
+    from repro.camera.bssa import GridSpec, slice_grid
+
+    grid = GridSpec(16, 16.0)
+
+    def scoped(v, w, img):
+        with jax.named_scope("slice"):
+            return slice_grid(v, w, img, grid)
+
+    g = spec((1, *grid.dims(2160, 3840)))
+    compiled = jax.jit(jax.vmap(scoped)).lower(
+        g, g, spec((1, 2160, 3840))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    hlo = compiled.as_text()
+    assert "vmap(slice)" in hlo
+    assert not [ln for ln in hlo.splitlines()
+                if " gather(" in ln and "slice" in ln]
 
 
 @pytest.mark.parametrize("name", ["fa_nn_l1", "fa_nn_l2"])
